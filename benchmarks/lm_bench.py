@@ -8,7 +8,6 @@ Emits ``name,us_per_call,derived`` CSV like the other benchmark sections.
 
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 import time
@@ -97,9 +96,10 @@ def _run_inner() -> None:
 
 
 def main() -> None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    from repro.launch.stencil import CPU_CHILDREN_NOTE, worker_env
+
+    env = worker_env(local_devices=8)
+    print(CPU_CHILDREN_NOTE, flush=True)
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.lm_bench", "--inner"],
         env=env, capture_output=True, text=True, timeout=1800,

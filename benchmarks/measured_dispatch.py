@@ -13,7 +13,6 @@ Run standalone (spawns itself with the 8-device XLA flag when needed):
 
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 
@@ -57,9 +56,10 @@ def _run_inner() -> None:
 
 def main() -> None:
     """Always spawn a fresh interpreter so the 8-device flag precedes jax init."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    from repro.launch.stencil import CPU_CHILDREN_NOTE, worker_env
+
+    env = worker_env(local_devices=8)
+    print(CPU_CHILDREN_NOTE, flush=True)
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.measured_dispatch", "--inner"],
         env=env, capture_output=True, text=True, timeout=1200,

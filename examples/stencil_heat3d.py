@@ -1,25 +1,34 @@
 """The paper's workload end-to-end: 3-D Jacobi (heat) iteration on a device
 mesh with standard / persistent / partitioned halo exchanges.
 
-Runs on 8 fake CPU devices (the flag below must precede the jax import).
+Runs in one process on the devices JAX finds: a chip, a TPU host, or
+virtual CPU devices pinned from outside, e.g.
 
-    PYTHONPATH=src python examples/stencil_heat3d.py [--cycles 20] [--size 32]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/stencil_heat3d.py [--cycles 20] [--size 32]
 """
-
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compat import make_mesh
-from repro.kernels.stencil27 import jacobi_weights, stencil27_ref
-from repro.stencil import Domain, comb_measure, periodic_oracle_step
+from repro.core.compile_cache import enable_compile_cache
+from repro.kernels.stencil27 import jacobi_weights
+from repro.stencil import (
+    Domain,
+    comb_measure,
+    periodic_oracle_step,
+    stencil27_update,
+)
 from repro.stencil.strategies import available_strategies
+
+
+def mesh_shape(n_devices: int) -> tuple[int, int]:
+    """(pz, py) over every device: py takes a factor of 2 where it can."""
+    py = 2 if n_devices % 2 == 0 else 1
+    return n_devices // py, py
 
 
 def main() -> None:
@@ -38,8 +47,8 @@ def main() -> None:
 
     ap.add_argument("--packer", choices=available_packers(), default="slice",
                     help="transport-layer pack backend every message stages "
-                         "through (pallas = the Comb-style copy kernel; "
-                         "falls back to its oracle off-TPU)")
+                         "through (pallas = the Comb-style copy kernel on "
+                         "TPU, its jnp oracle elsewhere)")
     ap.add_argument("--no-coalesce", action="store_true",
                     help="disable wire-buffer coalescing (per-message "
                          "pack/permute/unpack instead of one buffer + one "
@@ -47,16 +56,13 @@ def main() -> None:
     args = ap.parse_args()
     coalesce = not args.no_coalesce
 
-    mesh = make_mesh((4, 2), ("pz", "py"))  # compat shim handles axis_types
+    enable_compile_cache()
+    devices = jax.devices()
+    mesh = make_mesh(mesh_shape(len(devices)), ("pz", "py"), devices=devices)
     dom = Domain(mesh, global_interior=(args.size, args.size, args.size // 2),
                  mesh_axes=("pz", "py", None))
     w = jacobi_weights()
-
-    def update(xl):
-        # periodic wrap on the undecomposed x-axis, then 27-point Jacobi
-        xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
-        interior = stencil27_ref(xp, jnp.asarray(w))
-        return jax.lax.dynamic_update_slice(xl, interior, (1, 1, 0))
+    update = stencil27_update(w)
 
     from repro.stencil import StrategyConfig
 

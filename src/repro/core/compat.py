@@ -1,54 +1,40 @@
-"""Version-compatibility shims over drifting jax APIs.
+"""Thin wrappers over the jax APIs this codebase leans on (jax 0.9).
 
-The repo targets the pinned container environment but must survive the API
-drift between jax 0.4.x and 0.8.x that hits exactly the surfaces this
-codebase leans on:
+Every call site in src/, tests/ and benchmarks/ goes through these wrappers,
+so the defaults the repo relies on live in one place:
 
-* ``jax.shard_map``           — top-level alias + ``check_vma`` kwarg are new;
-  older releases only have ``jax.experimental.shard_map.shard_map`` with the
-  ``check_rep`` kwarg.
-* ``jax.sharding.AxisType``   — introduced with the explicit-sharding work;
-  absent on 0.4.x (where every mesh axis is implicitly "auto").
-* ``jax.make_mesh(axis_types=...)`` — the kwarg follows ``AxisType``.
-* ``Compiled.cost_analysis()``  — returns a dict on new jax, a one-element
-  list of dicts on 0.4.x.
-
-Every call site in src/, tests/ and benchmarks/ goes through these wrappers
-instead of feature-testing jax inline.
+* ``shard_map``  — the manual collectives are not expressible under the VMA
+  checker, so it is off by default;
+* ``make_mesh``  — every axis is ``AxisType.Auto``;
+* ``distributed_initialize`` — a bounded coordinator connect;
+* ``cost_analysis_dict`` — an empty analysis normalizes to ``{}``.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Callable, Sequence
 
 import jax
+from jax import lax
 
 __all__ = [
     "shard_map",
     "make_mesh",
-    "axis_type_auto",
+    "set_mesh",
     "axis_size",
+    "pallas_tpu_compiler_params",
+    "distributed_initialize",
     "cost_analysis_dict",
-    "enable_cpu_collectives",
 ]
 
 
 def axis_size(axis_name: str) -> int:
     """Static size of a named mesh axis, inside ``shard_map``.
 
-    ``lax.axis_size`` is new jax; on 0.4.x ``jax.core.axis_frame(name)``
-    returns the size (an int, or a frame carrying ``.size`` on some
-    releases).  Must stay a *python int* — the halo code unrolls loops and
-    builds permutation tables from it at trace time.
+    A *python int* — the halo code unrolls loops and builds permutation
+    tables from it at trace time.
     """
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    size = getattr(frame, "size", frame)
-    return int(size)
+    return lax.axis_size(axis_name)
 
 
 def shard_map(
@@ -59,34 +45,10 @@ def shard_map(
     out_specs: Any,
     check: bool = False,
 ) -> Callable:
-    """``jax.shard_map`` with the replication/VMA check disabled by default.
-
-    ``check`` maps to ``check_vma`` (new jax) or ``check_rep`` (old jax) —
-    the manual collectives in :mod:`repro.core.halo` and the models are not
-    expressible under either checker.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check,
-            )
-        except TypeError:  # jax with top-level alias but pre-VMA kwarg
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check,
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """``jax.shard_map`` with the VMA check disabled by default."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check,
     )
-
-
-def axis_type_auto() -> Any | None:
-    """``jax.sharding.AxisType.Auto`` where it exists, else ``None``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else axis_type.Auto
 
 
 def make_mesh(
@@ -95,60 +57,24 @@ def make_mesh(
     *,
     devices: Sequence[Any] | None = None,
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with auto axis types when the installed jax has them.
-
-    On jax without ``AxisType`` every axis is already auto-typed, so the
-    kwarg is simply dropped.
-    """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    auto = axis_type_auto()
-    if auto is not None and "axis_types" in inspect.signature(
-        jax.make_mesh
-    ).parameters:
-        kwargs["axis_types"] = (auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis auto-typed."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def set_mesh(mesh: jax.sharding.Mesh) -> Any:
-    """Context manager installing ``mesh`` as the ambient mesh for ``jit``.
-
-    ``jax.set_mesh`` is new jax; on 0.4.x a ``Mesh`` is itself the context
-    manager with the same sharding-resolution effect for these programs.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh for ``jit``."""
+    return jax.set_mesh(mesh)
 
 
 def pallas_tpu_compiler_params(**kwargs: Any) -> Any:
-    """``pltpu.CompilerParams`` (new name) / ``pltpu.TPUCompilerParams`` (old).
-
-    Same kwargs (``dimension_semantics`` etc.); only the class name drifted.
-    """
+    """``pltpu.CompilerParams`` (``dimension_semantics`` etc.)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def enable_cpu_collectives() -> None:
-    """Turn on cross-process collectives for the CPU backend (gloo).
-
-    jax 0.4.x needs ``jax_cpu_collectives_implementation`` flipped to
-    ``"gloo"`` *before* backend init or multi-process ``ppermute`` on CPU
-    fails with "Multiprocess computations aren't implemented on the CPU
-    backend"; newer jax selects a CPU collectives implementation
-    automatically (and may drop the option), so unknown-option errors are
-    swallowed.  Must run before the first device query of the process.
-    """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # newer jax: option gone, collectives already wired
+    return pltpu.CompilerParams(**kwargs)
 
 
 def distributed_initialize(
@@ -163,31 +89,17 @@ def distributed_initialize(
     Without a bound, a worker whose coordinator died before binding blocks
     in the barrier forever (the zombie-grid failure mode
     :func:`repro.launch.stencil.launch_grid` must reap).
-    ``initialization_timeout`` is feature-detected: jax versions that
-    predate the kwarg fall back to the unbounded call (the launcher-side
-    reap still bounds the grid).
     """
-    import inspect
-
     kwargs: dict[str, Any] = dict(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
     )
     if timeout is not None:
-        params = inspect.signature(jax.distributed.initialize).parameters
-        if "initialization_timeout" in params:
-            kwargs["initialization_timeout"] = max(1, int(timeout))
+        kwargs["initialization_timeout"] = max(1, int(timeout))
     jax.distributed.initialize(**kwargs)
 
 
 def cost_analysis_dict(compiled: Any) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` to a flat dict.
-
-    jax 0.4.x returns ``[{...}]`` (one entry per program); newer jax returns
-    the dict directly.  An empty analysis normalizes to ``{}``.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``Compiled.cost_analysis()`` as a plain dict (``{}`` when empty)."""
+    return dict(compiled.cost_analysis() or {})

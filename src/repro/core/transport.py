@@ -18,8 +18,8 @@ a :class:`Packer` and a :class:`Transport` chosen by *name*:
   ``"slice"`` is the inline ``lax.slice``/``dynamic_update_slice`` staging
   the halo code historically did; ``"pallas"`` routes through the
   :mod:`repro.kernels.pack` VMEM-tiled copy kernel (Comb's OpenMP pack
-  kernels), falling back to its jnp oracle off-TPU so CPU CI exercises
-  identical semantics.  ``"bf16"`` and ``"scaled-int8"`` are the
+  kernels) on TPU, and through its jnp oracle on other backends so CPU CI
+  exercises identical semantics.  ``"bf16"`` and ``"scaled-int8"`` are the
   wire-compressed packers: the slab is re-encoded for the wire (bf16 cast /
   fixed-scale int8 quantization) and the block dtype restored on unpack —
   lossy within :meth:`Packer.wire_tolerance`, shrinking
@@ -550,8 +550,7 @@ class Packer(abc.ABC):
         """Fill one coalesced 1-D wire buffer: every segment's slab packed
         at its static offset.  The default stages each segment through
         :meth:`pack` and concatenates (offsets are consecutive by
-        construction); kernel-backed packers override this with a single
-        fused gather-pack launch."""
+        construction)."""
         bufs = [
             jnp.ravel(self.pack(x, s.src_start, s.shape))
             for s in layout.segments
@@ -609,54 +608,55 @@ class SlicePacker(Packer):
 class PallasPacker(Packer):
     """Comb-pack-kernel analogue: the VMEM-tiled contiguous copy of
     :mod:`repro.kernels.pack`, extended to the N-D slabs the halo schedules
-    emit (faces, edges, corners, partitions) via a 2-D (lead, lane) view.
+    emit (faces, edges, corners, partitions) via a lane-dense 2-D
+    (lead, lane) view.  A coalesced buffer is filled with one kernel copy
+    per segment.
 
-    Off-TPU the kernel wrappers fall back to their jnp oracle, so the
-    packer is CI-runnable on virtual CPU devices with bit-identical
-    results; ``force_kernel``/``interpret`` pin the Pallas interpreter path
-    for kernel-parity tests.
+    The kernel runs on TPU; elsewhere the packer runs the kernel's jnp
+    oracle (:func:`repro.kernels.use_kernel` decides, once per call), so
+    it is CI-runnable on virtual CPU devices with bit-identical results.
+    ``force_kernel``/``interpret`` pin the Pallas interpreter path for
+    kernel-parity tests.  ``wire_dtype`` re-encodes the slab for the wire
+    (``None`` ships the block dtype unchanged).
     """
 
     name: str = "pallas"
     force_kernel: bool = False
     interpret: bool = False
+    wire_dtype: Any = None
 
     def pack(self, x, start, shape):
-        from repro.kernels.pack.ops import pack_slab
+        from repro.kernels import use_kernel
+        from repro.kernels.pack import pack_slab, pack_slab_ref
 
         limits = [s + n for s, n in zip(start, shape)]
         slab = lax.slice(x, list(start), limits)
-        return pack_slab(
-            slab, force_kernel=self.force_kernel, interpret=self.interpret
-        )
+        if use_kernel(self.force_kernel):
+            return pack_slab(slab, out_dtype=self.wire_dtype,
+                             interpret=self.interpret)
+        return pack_slab_ref(slab, out_dtype=self.wire_dtype)
 
     def unpack(self, x, buf, dst_start, shape):
-        from repro.kernels.pack.ops import unpack_slab
+        from repro.kernels import use_kernel
+        from repro.kernels.pack import unpack_slab, unpack_slab_ref
 
-        ghost = unpack_slab(
-            buf, tuple(shape), out_dtype=x.dtype,
-            force_kernel=self.force_kernel, interpret=self.interpret,
-        )
+        if use_kernel(self.force_kernel):
+            ghost = unpack_slab(buf, tuple(shape), out_dtype=x.dtype,
+                                interpret=self.interpret)
+        else:
+            ghost = unpack_slab_ref(buf, tuple(shape), out_dtype=x.dtype)
         return lax.dynamic_update_slice(x, ghost, tuple(dst_start))
-
-    def pack_coalesced(self, x, layout):
-        # Comb's combined pack: ONE kernel launch fills the whole coalesced
-        # buffer instead of one tiled copy per slab.
-        from repro.kernels.pack.ops import gather_pack
-
-        return gather_pack(
-            x, layout.segments, total=layout.total,
-            force_kernel=self.force_kernel, interpret=self.interpret,
-        )
 
     def _unpack_segment(self, x, seg, s):
         # unpack_slab consumes the kernel's 2-D (lead, lane) wire view
-        lead = s.numel // s.shape[-1] if len(s.shape) > 1 else 1
-        return self.unpack(x, seg.reshape(lead, -1), s.dst_start, s.shape)
+        from repro.kernels.pack import view_2d
+
+        return self.unpack(x, seg.reshape(view_2d(s.shape)), s.dst_start,
+                           s.shape)
 
 
 @dataclasses.dataclass(frozen=True)
-class Bf16Packer(Packer):
+class Bf16Packer(PallasPacker):
     """Wire-compressed packer: the slab crosses the wire as ``bfloat16``.
 
     ``pack`` stages the window through the :mod:`repro.kernels.pack` slab
@@ -668,30 +668,7 @@ class Bf16Packer(Packer):
     """
 
     name: str = "bf16"
-
-    def pack(self, x, start, shape):
-        from repro.kernels.pack.ops import pack_slab
-
-        limits = [s + n for s, n in zip(start, shape)]
-        slab = lax.slice(x, list(start), limits)
-        return pack_slab(slab, out_dtype=jnp.bfloat16)
-
-    def unpack(self, x, buf, dst_start, shape):
-        from repro.kernels.pack.ops import unpack_slab
-
-        ghost = unpack_slab(buf, tuple(shape), out_dtype=x.dtype)
-        return lax.dynamic_update_slice(x, ghost, tuple(dst_start))
-
-    def pack_coalesced(self, x, layout):
-        # one fused gather-pack launch, casting to the bf16 wire on the fly
-        from repro.kernels.pack.ops import gather_pack
-
-        return gather_pack(x, layout.segments, total=layout.total,
-                           out_dtype=jnp.bfloat16)
-
-    def _unpack_segment(self, x, seg, s):
-        lead = s.numel // s.shape[-1] if len(s.shape) > 1 else 1
-        return self.unpack(x, seg.reshape(lead, -1), s.dst_start, s.shape)
+    wire_dtype: Any = jnp.bfloat16
 
     def wire_itemsize(self, dtype):
         return 2  # the wire dtype is always bfloat16

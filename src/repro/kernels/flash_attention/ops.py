@@ -9,12 +9,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_kernel
 from repro.kernels.flash_attention.flash import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 
 def _use_kernel(sq: int, skv: int, d: int, block_q: int, block_kv: int) -> bool:
-    if jax.default_backend() != "tpu":
+    if not use_kernel():
         return False
     bq, bkv = min(block_q, sq), min(block_kv, skv)
     return sq % bq == 0 and skv % bkv == 0 and d % 128 == 0

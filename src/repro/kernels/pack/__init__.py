@@ -1,15 +1,15 @@
-from repro.kernels.pack.pack import gather_pack_1d, pack_2d, unpack_2d
+from repro.kernels.pack.pack import pack_2d, unpack_2d
 from repro.kernels.pack.ops import (
-    gather_pack, pack_face, unpack_face, pack_slab, unpack_slab,
+    pack_face, unpack_face, pack_slab, unpack_slab,
 )
 from repro.kernels.pack.ref import (
-    gather_pack_ref,
     pack_2d_ref, unpack_2d_ref, pack_face_ref, pack_slab_ref, unpack_slab_ref,
+    view_2d,
 )
 
 __all__ = [
     "pack_2d", "unpack_2d", "pack_face", "unpack_face",
-    "pack_slab", "unpack_slab", "gather_pack", "gather_pack_1d",
+    "pack_slab", "unpack_slab",
     "pack_2d_ref", "unpack_2d_ref", "pack_face_ref",
-    "pack_slab_ref", "unpack_slab_ref", "gather_pack_ref",
+    "pack_slab_ref", "unpack_slab_ref", "view_2d",
 ]

@@ -1,29 +1,22 @@
 """Public jit'd wrappers composing slice -> pack kernel -> (exchange) -> unpack.
 
-``pack_slab`` / ``unpack_slab`` are what the transport layer's ``pallas``
-packer uses (:class:`repro.core.transport.PallasPacker`): they carry any N-D
+``pack_slab`` / ``unpack_slab`` are what the transport layer's kernel-backed
+packers use (:class:`repro.core.transport.PallasPacker`): they carry any N-D
 slab the halo schedules emit — full-extent sequential faces, the fused
 schedule's ``3^D - 1`` face/edge/corner blocks, and clipped partitions —
-through the 2-D (lead, lane) kernel view.  ``pack_face`` / ``unpack_face``
-are the face-level forms (slice by axis/side baked in).  On non-TPU backends
-every wrapper falls back to the jnp oracle so CPU tests and smoke runs
-exercise identical semantics.
+through the lane-dense 2-D (lead, lane) kernel view (:func:`view_2d`).
+``pack_face`` / ``unpack_face`` are the face-level forms (slice by axis/side
+baked in).  Every wrapper runs the kernel; which backend gets the kernel and
+which gets the jnp oracle (:mod:`repro.kernels.pack.ref`) is the packer's
+choice, made once in :func:`repro.kernels.use_kernel`.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels.pack.pack import gather_pack_1d, pack_2d, unpack_2d
-from repro.kernels.pack import ref as _ref
-
-
-def _to_2d(slab: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
-    shape = slab.shape
-    if slab.ndim == 1:
-        return slab.reshape(1, -1), shape
-    return slab.reshape(-1, shape[-1]), shape
+from repro.kernels.pack.pack import pack_2d, unpack_2d
+from repro.kernels.pack.ref import view_2d
 
 
 def pack_slab(
@@ -31,16 +24,12 @@ def pack_slab(
     *,
     out_dtype=None,
     scale: float = 1.0,
-    force_kernel: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """Pack an N-D slab (face, edge, corner, or partition block) into a
     contiguous 2-D wire buffer via the tiled copy kernel."""
-    flat, _ = _to_2d(slab)
-    if force_kernel or jax.default_backend() == "tpu":
-        return pack_2d(flat, out_dtype=out_dtype, scale=scale,
-                       interpret=interpret)
-    return _ref.pack_2d_ref(flat, out_dtype=out_dtype, scale=scale)
+    return pack_2d(slab.reshape(view_2d(slab.shape)), out_dtype=out_dtype,
+                   scale=scale, interpret=interpret)
 
 
 def unpack_slab(
@@ -49,60 +38,12 @@ def unpack_slab(
     *,
     out_dtype=None,
     scale: float = 1.0,
-    force_kernel: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """Inverse of :func:`pack_slab`: wire buffer back to the slab ``shape``."""
-    if force_kernel or jax.default_backend() == "tpu":
-        vals = unpack_2d(buf, out_dtype=out_dtype, scale=scale,
-                         interpret=interpret)
-    else:
-        vals = _ref.unpack_2d_ref(buf, out_dtype=out_dtype, scale=scale)
+    vals = unpack_2d(buf, out_dtype=out_dtype, scale=scale,
+                     interpret=interpret)
     return vals.reshape(shape)
-
-
-#: the gather kernel is untiled (the whole local block rides in VMEM, so
-#: every window is gatherable in one launch); blocks beyond this budget
-#: fall back to the jnp gather, which XLA tiles itself.  ~16 MB VMEM per
-#: core, minus headroom for the output buffer and double-buffering.
-GATHER_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
-
-
-def gather_pack(
-    x: jax.Array,
-    segments,
-    *,
-    total: int,
-    out_dtype=None,
-    scale: float = 1.0,
-    force_kernel: bool = False,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fill one coalesced wire buffer in a single fused launch.
-
-    ``segments`` is a static offset table — ``WireSegment``-like values (or
-    ``(offset, src_start, shape)`` tuples) tiling ``[0, total)`` in order —
-    of every slab bound for one neighbor
-    (:meth:`repro.core.transport.Packer.pack_coalesced`).  One kernel launch
-    gathers all windows instead of one tiled copy per slab; off-TPU (and
-    for blocks too large for the untiled kernel's VMEM residency,
-    :data:`GATHER_VMEM_BUDGET_BYTES`) the jnp oracle keeps identical
-    semantics.
-    """
-    segs = tuple(
-        (int(s[0]), tuple(int(v) for v in s[1]), tuple(int(v) for v in s[2]))
-        if isinstance(s, tuple)
-        else (int(s.offset), tuple(int(v) for v in s.src_start),
-              tuple(int(v) for v in s.shape))
-        for s in segments
-    )
-    fits_vmem = x.size * x.dtype.itemsize <= GATHER_VMEM_BUDGET_BYTES
-    if force_kernel or (jax.default_backend() == "tpu" and fits_vmem):
-        return gather_pack_1d(x, segments=segs, total=total,
-                              out_dtype=out_dtype, scale=scale,
-                              interpret=interpret)
-    return _ref.gather_pack_ref(x, segs, total=total, out_dtype=out_dtype,
-                                scale=scale)
 
 
 def pack_face(
@@ -113,7 +54,6 @@ def pack_face(
     *,
     out_dtype=None,
     scale: float = 1.0,
-    force_kernel: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """Pack one interior boundary face into a contiguous (possibly
@@ -125,10 +65,8 @@ def pack_face(
         slab = jax.lax.slice_in_dim(x, size - 2 * halo, size - halo, axis=array_axis)
     else:
         raise ValueError(side)
-    flat, _ = _to_2d(slab)
-    if force_kernel or jax.default_backend() == "tpu":
-        return pack_2d(flat, out_dtype=out_dtype, scale=scale, interpret=interpret)
-    return _ref.pack_2d_ref(flat, out_dtype=out_dtype, scale=scale)
+    return pack_slab(slab, out_dtype=out_dtype, scale=scale,
+                     interpret=interpret)
 
 
 def unpack_face(
@@ -139,18 +77,14 @@ def unpack_face(
     halo: int,
     *,
     scale: float = 1.0,
-    force_kernel: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
     """Unpack a received contiguous buffer into the ghost rim of ``x``."""
     size = x.shape[array_axis]
     ghost_shape = list(x.shape)
     ghost_shape[array_axis] = halo
-    if force_kernel or jax.default_backend() == "tpu":
-        vals = unpack_2d(buf, out_dtype=x.dtype, scale=scale, interpret=interpret)
-    else:
-        vals = _ref.unpack_2d_ref(buf, out_dtype=x.dtype, scale=scale)
-    ghost = vals.reshape(ghost_shape)
+    ghost = unpack_slab(buf, tuple(ghost_shape), out_dtype=x.dtype,
+                        scale=scale, interpret=interpret)
     starts = [0] * x.ndim
     starts[array_axis] = 0 if side == "low" else size - halo
     return jax.lax.dynamic_update_slice(x, ghost, tuple(starts))

@@ -73,49 +73,6 @@ def pack_2d(
     return out
 
 
-def _gather_pack_kernel(x_ref, o_ref, *, segments, scale: float):
-    # static unroll over the layout's offset table: ONE launch fills the
-    # whole coalesced buffer (the fused analogue of Comb's combined pack)
-    for offset, start, shape in segments:
-        window = tuple(pl.dslice(b, n) for b, n in zip(start, shape))
-        vals = x_ref[window]
-        if scale != 1.0:
-            vals = vals.astype(jnp.float32) * scale
-        n = 1
-        for d in shape:
-            n *= d
-        o_ref[pl.dslice(offset, n)] = vals.reshape(-1).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("segments", "total", "out_dtype", "scale", "interpret"),
-)
-def gather_pack_1d(
-    x: jax.Array,
-    *,
-    segments: tuple[tuple[int, tuple[int, ...], tuple[int, ...]], ...],
-    total: int,
-    out_dtype=None,
-    scale: float = 1.0,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused gather-pack: copy every ``(offset, start, shape)`` window of
-    ``x`` into a contiguous 1-D wire buffer in one kernel launch (with the
-    same on-the-fly convert/scale fusions as :func:`pack_2d`).
-
-    Untiled: the whole block is one VMEM operand so arbitrary windows can
-    be gathered in a single launch — callers must bound the block size
-    (``ops.GATHER_VMEM_BUDGET_BYTES``); halo blocks beyond it go through
-    the jnp gather, which XLA tiles."""
-    out_dtype = out_dtype or x.dtype
-    return pl.pallas_call(
-        functools.partial(_gather_pack_kernel, segments=segments, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((total,), out_dtype),
-        interpret=interpret,
-    )(x)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("out_dtype", "scale", "block_lead", "block_lane", "interpret"),

@@ -18,12 +18,33 @@ def unpack_2d_ref(buf: jax.Array, *, out_dtype=None, scale: float = 1.0) -> jax.
     return pack_2d_ref(buf, out_dtype=out_dtype, scale=(1.0 / scale if scale != 1.0 else 1.0))
 
 
+#: lanes a 2-D slab view aims for: one TPU vector register row
+_LANES = 128
+
+
+def view_2d(shape: tuple[int, ...]) -> tuple[int, int]:
+    """Lane-dense ``(lead, lane)`` view of a row-major slab.
+
+    The lane axis takes the trailing dims, innermost first, until it holds
+    at least 128 elements (or every dim), so a slab whose trailing dims are
+    1 or halo-wide still gets wide rows instead of an ``(N, 1)`` column.
+    """
+    lane, k = 1, len(shape)
+    while k > 0 and lane < _LANES:
+        k -= 1
+        lane *= shape[k]
+    lead = 1
+    for d in shape[:k]:
+        lead *= d
+    return lead, lane
+
+
 def pack_slab_ref(
     slab: jax.Array, *, out_dtype=None, scale: float = 1.0
 ) -> jax.Array:
     """N-D slab -> contiguous 2-D wire buffer (jnp oracle of ``pack_slab``)."""
-    flat = slab.reshape(-1, slab.shape[-1]) if slab.ndim > 1 else slab.reshape(1, -1)
-    return pack_2d_ref(flat, out_dtype=out_dtype, scale=scale)
+    return pack_2d_ref(slab.reshape(view_2d(slab.shape)), out_dtype=out_dtype,
+                       scale=scale)
 
 
 def unpack_slab_ref(
@@ -31,31 +52,6 @@ def unpack_slab_ref(
 ) -> jax.Array:
     """Wire buffer -> slab of ``shape`` (jnp oracle of ``unpack_slab``)."""
     return unpack_2d_ref(buf, out_dtype=out_dtype, scale=scale).reshape(shape)
-
-
-def gather_pack_ref(
-    x: jax.Array,
-    segments,
-    *,
-    total: int,
-    out_dtype=None,
-    scale: float = 1.0,
-) -> jax.Array:
-    """jnp oracle of the fused gather-pack: every ``(offset, start, shape)``
-    window of ``x`` laid end-to-end in one 1-D wire buffer."""
-    out_dtype = out_dtype or x.dtype
-    bufs = []
-    covered = 0
-    for offset, start, shape in segments:
-        assert offset == covered, "segments must tile the buffer in order"
-        limits = [s + n for s, n in zip(start, shape)]
-        slab = jax.lax.slice(x, list(start), limits).reshape(-1)
-        if scale != 1.0:
-            slab = slab.astype(jnp.float32) * scale
-        bufs.append(slab.astype(out_dtype))
-        covered += bufs[-1].size
-    assert covered == total, (covered, total)
-    return bufs[0] if len(bufs) == 1 else jnp.concatenate(bufs)
 
 
 def pack_face_ref(
@@ -70,5 +66,5 @@ def pack_face_ref(
         slab = jax.lax.slice_in_dim(x, size - 2 * halo, size - halo, axis=array_axis)
     else:
         raise ValueError(side)
-    flat = slab.reshape(-1, slab.shape[-1]) if slab.ndim > 1 else slab.reshape(1, -1)
-    return pack_2d_ref(flat, out_dtype=out_dtype, scale=scale)
+    return pack_2d_ref(slab.reshape(view_2d(slab.shape)), out_dtype=out_dtype,
+                       scale=scale)

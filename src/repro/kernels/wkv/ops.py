@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_kernel
 from repro.kernels.wkv.wkv import wkv_chunked
 from repro.kernels.wkv.ref import wkv_chunked_ref
 
@@ -26,7 +27,7 @@ def wkv(
         return x.transpose(0, 2, 1, 3).reshape(B * H, T, hd)
 
     uf = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, 1, hd)
-    if force_kernel or jax.default_backend() == "tpu":
+    if use_kernel(force_kernel):
         y = wkv_chunked(flat(r), flat(k), flat(v), flat(lw), uf, chunk=chunk,
                         interpret=interpret)
     else:

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x shape) cell on the
 production mesh and record memory / cost / collective analyses.
 
@@ -17,13 +14,14 @@ ShapeDtypeStructs (zero allocation).  Results are cached incrementally in
 results/dryrun/<cell>.json; reduced-depth (L=1, L=2) variants are also
 compiled for the roofline's scan-trip-count correction (DESIGN.md §6).
 
-(No ``from __future__`` import here: the XLA_FLAGS lines above must stay the
-very first statements of the file.)
+It compiles for 512 virtual CPU devices, which :func:`main` pins before the
+process's first device query (importing this module sets nothing).
 """
 
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -313,6 +311,10 @@ def all_cells() -> list[tuple[str, str]]:
 
 
 def main() -> None:
+    # a CPU rehearsal of the production mesh: XLA reads the device count at
+    # backend start-up, which has not happened yet
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -23,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         raise RuntimeError(
             f"need {n} devices for the production mesh, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "importing jax (launch/dryrun.py does this)"
+            "the first device query (launch/dryrun.py's main does this)"
         )
     return make_mesh(shape, axes, devices=devices[:n])
 

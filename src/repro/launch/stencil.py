@@ -47,6 +47,12 @@ PROCESS_ID_VAR = "REPRO_PROCESS_ID"
 CONNECT_TIMEOUT_VAR = "REPRO_CONNECT_TIMEOUT"
 
 _DEVICE_FLAG = "--xla_force_host_platform_device_count"
+#: printed by every launcher whose children :func:`worker_env` pins to the
+#: CPU, ahead of the children's output
+CPU_CHILDREN_NOTE = (
+    "# child processes run on virtual CPU devices (JAX_PLATFORMS=cpu): "
+    "their times are CPU times"
+)
 
 
 def pick_coordinator_port() -> int:
@@ -96,7 +102,9 @@ def worker_env(
 ) -> dict[str, str]:
     """The environment one worker process boots with.
 
-    Pins exactly ``local_devices`` virtual CPU devices (replacing any
+    Pins the worker to the CPU (``JAX_PLATFORMS=cpu``): a parent that holds
+    a chip must never start children that reach for it.  Pins exactly
+    ``local_devices`` virtual CPU devices (replacing any
     device-count pin inherited from the parent — the launcher may itself
     run under the 8-device test env — while preserving other XLA flags)
     and prepends this checkout's ``src`` to ``PYTHONPATH`` so spawned
@@ -108,6 +116,7 @@ def worker_env(
     env = dict(os.environ if base is None else base)
     flags = re.sub(rf"{_DEVICE_FLAG}=\d+", "", env.get("XLA_FLAGS", ""))
     env["XLA_FLAGS"] = f"{flags} {_DEVICE_FLAG}={local_devices}".strip()
+    env["JAX_PLATFORMS"] = "cpu"
     from repro.launch.membership import MEMBERSHIP_VAR
 
     if coordinator is not None:
@@ -138,17 +147,14 @@ def maybe_initialize_from_env() -> int:
     No-op (rank 0 of a 1-process world) when the variables are absent, so
     worker entry points stay runnable standalone.  Must be called before
     the process's first jax device query: ``jax.distributed.initialize``
-    cannot attach once the backend client exists.  CPU cross-process
-    collectives are switched on through
-    :func:`repro.core.compat.enable_cpu_collectives`.
+    cannot attach once the backend client exists.
     """
     coordinator = os.environ.get(COORDINATOR_VAR)
     if not coordinator:
         return 0
-    from repro.core import compat
-
-    compat.enable_cpu_collectives()
     import jax
+
+    from repro.core import compat
 
     num_processes = int(os.environ[NUM_PROCESSES_VAR])
     process_id = int(os.environ[PROCESS_ID_VAR])
@@ -567,6 +573,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             local_devices=args.devices_per_process,
             timeout=args.timeout,
         )
+        print(CPU_CHILDREN_NOTE)
         print(out, end="")
         return
 

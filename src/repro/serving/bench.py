@@ -301,10 +301,12 @@ def main(argv: Sequence[str] | None = None) -> int:
                     help="(internal) already inside the 8-device subprocess")
     args = ap.parse_args(argv)
     if not args.inner:
-        # re-exec with the virtual device grid pinned before jax initializes
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        env.setdefault("PYTHONPATH", "src")
+        # re-exec on the CPU with the virtual device grid pinned before jax
+        # initializes
+        from repro.launch.stencil import CPU_CHILDREN_NOTE, worker_env
+
+        env = worker_env(local_devices=8)
+        print(CPU_CHILDREN_NOTE, flush=True)
         out = subprocess.run(
             [sys.executable, "-m", "repro.serving.bench", "--inner",
              *([a for a in (sys.argv[1:] if argv is None else list(argv))])],

@@ -1,4 +1,9 @@
-from repro.stencil.domain import Domain, periodic_oracle_step, reference_exchange
+from repro.stencil.domain import (
+    Domain,
+    periodic_oracle_step,
+    reference_exchange,
+    stencil27_update,
+)
 from repro.stencil.exchange import ExchangeDriver
 from repro.stencil.strategies import (
     ExchangeStrategy,
@@ -30,7 +35,8 @@ def __getattr__(name):
     raise AttributeError(name)
 
 __all__ = [
-    "Domain", "periodic_oracle_step", "reference_exchange", "ExchangeDriver",
+    "Domain", "periodic_oracle_step", "reference_exchange", "stencil27_update",
+    "ExchangeDriver",
     "ExchangeStrategy", "StrategyConfig", "available_strategies",
     "get_strategy", "make_driver", "register_strategy",
     "CycleResult", "comb_measure", "result_label", "run_cycles",

@@ -221,6 +221,7 @@ def comb_measure(
     n_cycles: int = 50,
     repeats: int = 3,
     seed: int = 0,
+    make_input: Callable[[], jax.Array] | None = None,
 ) -> dict[str, CycleResult]:
     """Measure all strategies on one domain; checksums must agree.
 
@@ -231,7 +232,10 @@ def comb_measure(
     packers (the §VI packing axis); when the same key is swept more than
     once (e.g. partitioned at several partition counts) later entries get a
     ``name#pN`` key — and a ``#2``/``#3`` ordinal when name *and* partition
-    count repeat — so no measurement is silently dropped.
+    count repeat — so no measurement is silently dropped.  ``make_input``
+    builds each strategy's fresh input (default ``domain.random(seed)``);
+    a caller whose data is large builds it once and hands each strategy a
+    device copy.
     """
     results: dict[str, CycleResult] = {}
     for strategy in strategies:
@@ -246,7 +250,7 @@ def comb_measure(
             while label in results:
                 label = f"{base}#{n}"
                 n += 1
-        x = domain.random(seed)
+        x = domain.random(seed) if make_input is None else make_input()
         driver = make_driver(
             config,
             domain.mesh,

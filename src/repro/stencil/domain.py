@@ -124,7 +124,8 @@ class Domain:
             return jax.make_array_from_callback(
                 stored.shape, sharding, lambda idx: stored[idx]
             )
-        return jax.device_put(jnp.asarray(stored), sharding)
+        # straight from the host array: each device gets only its shard
+        return jax.device_put(stored, sharding)
 
     def stored_from_interior(self, interior: np.ndarray) -> np.ndarray:
         """Host-side stored (ghost-carrying) layout of a dense interior.
@@ -318,6 +319,43 @@ def overlapped_update(
         )
         out = jax.lax.dynamic_update_slice(out, piece, region.dst)
     return out
+
+
+#: the implementations :func:`stencil27_update` can run, by name
+UPDATE_IMPLS = ("xla", "pallas")
+
+
+def stencil27_update(
+    weights, *, impl: str = "xla", interpret: bool = False
+) -> Callable[[jax.Array], jax.Array]:
+    """The 27-point update of one local ghosted 3-D block.
+
+    The block carries ghost rims on its two leading (decomposed) axes and
+    none on its last axis, which is undecomposed and periodic: the update
+    wraps that axis locally, applies the stencil and writes the new
+    interior back, leaving the ghost rims untouched (the
+    :func:`interior_halo_split` contract, so ``overlap`` can split it).
+    One exchange plus this update is one Comb cycle of
+    :func:`periodic_oracle_step`.  ``impl`` names the stencil: ``"xla"``
+    is :func:`repro.kernels.stencil27.stencil27_ref` under XLA,
+    ``"pallas"`` the :func:`repro.kernels.stencil27.stencil27` kernel
+    (``interpret`` runs it in the Pallas interpreter, off the chip).
+    """
+    from repro.kernels.stencil27 import stencil27, stencil27_ref
+
+    if impl not in UPDATE_IMPLS:
+        raise ValueError(f"impl must be one of {UPDATE_IMPLS}, got {impl!r}")
+    w = jnp.asarray(weights)
+
+    def update(xl: jax.Array) -> jax.Array:
+        xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
+        if impl == "pallas":
+            interior = stencil27(xp, w, interpret=interpret)
+        else:
+            interior = stencil27_ref(xp, w)
+        return jax.lax.dynamic_update_slice(xl, interior, (1, 1, 0))
+
+    return update
 
 
 def periodic_oracle_step(interior: np.ndarray, weights: np.ndarray) -> np.ndarray:

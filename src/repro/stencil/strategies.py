@@ -425,13 +425,19 @@ class StandardStrategy(ExchangeStrategy):
             k = self.mesh.shape[name]
             _ = [(i, (i - 1) % k) for i in range(k)]
             _ = [(i, (i + 1) % k) for i in range(k)]
+        return self._jit()(x)
+
+    def _jit(self):
         if self._jitted is None:
             donate = (0,) if self.config.donate else ()
             self._jitted = jax.jit(self._build_step(), donate_argnums=donate)
-        return self._jitted(x)
+        return self._jitted
 
     def free(self) -> None:
         self._jitted = None
+
+    def compiled_text(self, example) -> str:
+        return self._jit().lower(example).compile().as_text()
 
 
 @register_strategy

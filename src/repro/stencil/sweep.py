@@ -714,6 +714,8 @@ def config_block(
 
 
 def main(argv: Sequence[str] | None = None) -> None:
+    from repro.launch.stencil import CPU_CHILDREN_NOTE
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--worker", metavar="CONFIG_JSON",
                     help="(internal) run one device-count's cells in-process")
@@ -867,6 +869,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             config = dataclasses.replace(
                 config, processes=args.processes, transport="multihost",
             )
+            print(CPU_CHILDREN_NOTE)
             records = run_sweep(config, timeout=args.timeout)
         else:
             # in-process: the device count must be pinned before jax
@@ -919,6 +922,7 @@ def main(argv: Sequence[str] | None = None) -> None:
         config = dataclasses.replace(
             config, processes=args.processes, transport="multihost",
         )
+    print(CPU_CHILDREN_NOTE)
     records = run_sweep(config, timeout=args.timeout)
     write_bench_json(records, args.out,
                      config=config_block(config, timeout=args.timeout,

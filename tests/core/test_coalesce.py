@@ -301,8 +301,8 @@ def test_coalesced_backends_observe_one_buffer_per_chain():
 
 
 def test_pallas_gather_pack_fills_buffer_in_one_launch():
-    """The fused gather-pack kernel (interpreter-pinned) produces the same
-    coalesced buffer as the per-slab reference concatenation."""
+    """The pack kernel (interpreter-pinned), one copy per segment, produces
+    the same coalesced buffer as the per-slab reference concatenation."""
     p = PallasPacker(name="pallas-gather-test", force_kernel=True,
                      interpret=True)
     rng = np.random.default_rng(9)

@@ -388,3 +388,13 @@ def test_worker_env_stamps_connect_timeout_and_membership():
     clean = worker_env(local_devices=2, base=stale)
     assert CONNECT_TIMEOUT_VAR not in clean
     assert MEMBERSHIP_VAR not in clean
+
+
+def test_worker_env_pins_children_to_cpu():
+    """A launcher's children never reach for the chip its parent may hold:
+    the worker env pins the CPU whatever the parent's platform."""
+    from repro.launch.stencil import worker_env
+
+    env = worker_env(local_devices=2, base={"JAX_PLATFORMS": "tpu"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]

@@ -16,7 +16,7 @@ from repro.testing import given, settings, st  # hypothesis or deterministic fal
 
 from repro.kernels.pack import (
     pack_2d, pack_2d_ref, pack_face, unpack_face,
-    pack_slab, pack_slab_ref, unpack_slab, unpack_slab_ref,
+    pack_slab, pack_slab_ref, unpack_slab, unpack_slab_ref, view_2d,
 )
 
 
@@ -55,12 +55,12 @@ def test_pack_unpack_face_roundtrip(axis, side):
     rng = np.random.default_rng(2)
     x = jnp.asarray(rng.normal(size=(10, 12, 14)), jnp.float32)
     halo = 1
-    buf = pack_face(x, axis, side, halo, force_kernel=True, interpret=True)
+    buf = pack_face(x, axis, side, halo, interpret=True)
     # unpack into the *opposite* ghost of a neighbor block
     other = jnp.zeros_like(x)
     ghost_side = "high" if side == "low" else "low"
     filled = unpack_face(other, buf, axis, ghost_side, halo,
-                         force_kernel=True, interpret=True)
+                         interpret=True)
     size = x.shape[axis]
     if side == "low":
         want = jax.lax.slice_in_dim(x, halo, 2 * halo, axis=axis)
@@ -133,10 +133,10 @@ def test_pack_slab_kernel_matches_ref_on_halo_shapes(shape, names, halo):
     rng = np.random.default_rng(11)
     for slab_shape in _halo_slab_shapes(shape, names, halo):
         slab = jnp.asarray(rng.normal(size=slab_shape), jnp.float32)
-        got = pack_slab(slab, force_kernel=True, interpret=True)
+        got = pack_slab(slab, interpret=True)
         want = pack_slab_ref(slab)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        back = unpack_slab(got, slab_shape, force_kernel=True, interpret=True)
+        back = unpack_slab(got, slab_shape, interpret=True)
         np.testing.assert_array_equal(np.asarray(back), np.asarray(slab))
         np.testing.assert_array_equal(
             np.asarray(unpack_slab_ref(want, slab_shape)), np.asarray(slab)
@@ -152,63 +152,45 @@ def test_pack_slab_partition_windows_roundtrip():
     rng = np.random.default_rng(12)
     for part in msg.partitions():
         slab = jnp.asarray(rng.normal(size=part.shape), jnp.float32)
-        buf = pack_slab(slab, force_kernel=True, interpret=True)
-        back = unpack_slab(buf, part.shape, force_kernel=True, interpret=True)
+        buf = pack_slab(slab, interpret=True)
+        back = unpack_slab(buf, part.shape, interpret=True)
         np.testing.assert_array_equal(np.asarray(back), np.asarray(slab))
 
 
-def test_gather_pack_kernel_matches_ref_on_fused_tables():
-    """The fused gather-pack (interpreter) == jnp oracle on a whole fused
-    slab table coalesced into one buffer (the 3^D - 1 windows of a block)."""
-    from repro.core.halo import HaloSpec, fused_slab_table
-    from repro.kernels.pack import gather_pack, gather_pack_ref
-
-    shape, halo = (8, 6, 5), 1
-    spec = HaloSpec(mesh_axes=("px", "py", "pz"), array_axes=(0, 1, 2),
-                    halo=halo)
-    segments, offset = [], 0
-    for slab in fused_slab_table(shape, spec):
-        n = int(np.prod(slab.shape))
-        segments.append((offset, slab.src_start, slab.shape))
-        offset += n
-    rng = np.random.default_rng(14)
-    x = jnp.asarray(rng.normal(size=shape), jnp.float32)
-    got = gather_pack(x, segments, total=offset, force_kernel=True,
-                      interpret=True)
-    want = gather_pack_ref(x, segments, total=offset)
-    assert got.shape == (offset,)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # with the bf16 wire conversion fused into the same launch
-    got16 = gather_pack(x, segments, total=offset, out_dtype=jnp.bfloat16,
-                        force_kernel=True, interpret=True)
-    assert got16.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        np.asarray(got16),
-        np.asarray(gather_pack_ref(x, segments, total=offset,
-                                   out_dtype=jnp.bfloat16)),
-    )
+#: slabs whose trailing dims are 1 or halo-wide: the faces of a block whose
+#: last (lane) axis is decomposed
+THIN_TRAILING_SLABS = [
+    ((6, 200, 1), (6, 200)),
+    ((3, 100, 2), (3, 200)),
+    ((4, 300, 1, 1), (4, 300)),
+    ((2, 3, 50, 2), (2, 300)),
+    ((5, 7), (1, 35)),
+]
 
 
-def test_gather_pack_cpu_fallback_is_oracle():
-    from repro.kernels.pack import gather_pack, gather_pack_ref
-
-    x = jnp.arange(24.0).reshape(4, 6)
-    segments = ((0, (0, 0), (1, 6)), (6, (2, 1), (2, 3)))
-    np.testing.assert_array_equal(
-        np.asarray(gather_pack(x, segments, total=12)),
-        np.asarray(gather_pack_ref(x, segments, total=12)),
-    )
+@pytest.mark.parametrize("shape,view", THIN_TRAILING_SLABS)
+def test_pack_slab_lane_dense_view_roundtrip(shape, view):
+    """A slab with thin trailing dims packs through a lane-dense 2-D view
+    (never an (N, 1) column), and unpack restores it bit-exactly."""
+    rng = np.random.default_rng(15)
+    slab = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    assert view_2d(shape) == view
+    buf = pack_slab(slab, interpret=True)
+    assert buf.shape == view
+    np.testing.assert_array_equal(np.asarray(buf),
+                                  np.asarray(pack_slab_ref(slab)))
+    back = unpack_slab(buf, shape, interpret=True)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(slab))
 
 
 def test_pack_slab_wire_compression_roundtrip():
     """bf16 wire format on an N-D slab: bytes halve, values within bf16 eps."""
     rng = np.random.default_rng(13)
     slab = jnp.asarray(rng.normal(size=(2, 12, 7)), jnp.float32)
-    buf = pack_slab(slab, out_dtype=jnp.bfloat16, force_kernel=True,
-                    interpret=True)
+    buf = pack_slab(slab, out_dtype=jnp.bfloat16, interpret=True)
     assert buf.dtype == jnp.bfloat16 and buf.size == slab.size
     back = unpack_slab(buf, slab.shape, out_dtype=jnp.float32,
-                       force_kernel=True, interpret=True)
+                       interpret=True)
     np.testing.assert_allclose(np.asarray(back), np.asarray(slab),
                                rtol=1e-2, atol=1e-2)
 
@@ -250,7 +232,8 @@ def test_bf16_packer_wire_matches_slab_kernel():
     block = jnp.asarray(rng.normal(size=(6, 10)), jnp.float32)
     buf = get_packer("bf16").pack(block, (1, 2), (2, 7))
     want = pack_slab(
-        jax.lax.slice(block, (1, 2), (3, 9)), out_dtype=jnp.bfloat16
+        jax.lax.slice(block, (1, 2), (3, 9)), out_dtype=jnp.bfloat16,
+        interpret=True,
     )
     assert buf.dtype == jnp.bfloat16
     np.testing.assert_array_equal(
@@ -259,11 +242,14 @@ def test_bf16_packer_wire_matches_slab_kernel():
 
 
 def test_pack_slab_cpu_fallback_is_oracle():
-    """Off-TPU (no force_kernel) the wrapper IS the oracle — the pallas
-    packer's CPU fallback the equivalence matrix relies on."""
+    """Off-TPU (no force_kernel) the pallas packer packs through the oracle
+    — the CPU path the equivalence matrix relies on."""
+    from repro.core.transport import get_packer
+
     assert jax.default_backend() != "tpu", "test assumes CPU/virtual devices"
     rng = np.random.default_rng(14)
-    slab = jnp.asarray(rng.normal(size=(3, 9, 4)), jnp.float32)
+    block = jnp.asarray(rng.normal(size=(3, 9, 4)), jnp.float32)
     np.testing.assert_array_equal(
-        np.asarray(pack_slab(slab)), np.asarray(pack_slab_ref(slab))
+        np.asarray(get_packer("pallas").pack(block, (0, 0, 0), (3, 9, 4))),
+        np.asarray(pack_slab_ref(block)),
     )
