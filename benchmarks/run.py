@@ -6,9 +6,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   figures      — the paper's four figures (fig2..fig5), projected with the
                  calibrated Quartz-class model (configs/comb_paper.py)
   claims       — model vs the paper's quoted speedups (C1-C6)
-  measured     — REAL timings on this host: per-iteration dispatch/plan
-                 overhead of standard vs persistent vs partitioned (8 fake
-                 devices, subprocess)
   overlap      — HLO structural verification that partitioned exchanges
                  decompose into n_parts independent collectives
   sweep        — the §VI device x partition x message-size grid over all
@@ -47,13 +44,6 @@ def _section_claims(args) -> None:
 
     print("# === paper-claim validation (model vs quoted numbers) ===")
     figures.claims_table(emit)
-
-
-def _section_measured(args) -> None:
-    print("# === measured (real CPU timings, 8 fake devices) ===")
-    from benchmarks import measured_dispatch
-
-    measured_dispatch.main()
 
 
 def _section_overlap(args) -> None:
@@ -98,7 +88,6 @@ def _section_lm(args) -> None:
 SECTIONS = {
     "figures": _section_figures,
     "claims": _section_claims,
-    "measured": _section_measured,
     "overlap": _section_overlap,
     "sweep": _section_sweep,
     "fig_sweep": _section_fig_sweep,
@@ -106,7 +95,7 @@ SECTIONS = {
 }
 
 #: sections skipped under --fast (subprocess-heavy / real timings)
-SLOW_SECTIONS = ("measured", "overlap", "sweep", "fig_sweep", "lm")
+SLOW_SECTIONS = ("overlap", "sweep", "fig_sweep", "lm")
 
 
 def main() -> None:

@@ -32,6 +32,8 @@ from typing import Any, Callable, Hashable, Sequence
 import jax
 import numpy as np
 
+from repro.core import tracing
+
 
 def _abstractify(x: Any) -> Any:
     """Concrete array / ShapeDtypeStruct -> hashable abstract description."""
@@ -84,6 +86,10 @@ class CommPlan:
         self.schedule = None
         self.wire_layouts: tuple = ()
         self._freed = False
+        #: dispatches of the compiled executable, and the host seconds
+        #: spent in them (the ``plan.start`` span, traced or not)
+        self.starts = 0
+        self.start_seconds = 0.0
         t0 = time.perf_counter()
         kw: dict[str, Any] = dict(
             donate_argnums=donate_argnums, static_argnums=static_argnums
@@ -94,7 +100,7 @@ class CommPlan:
             kw["out_shardings"] = out_shardings
         jitted = jax.jit(fn, **kw)
         ctx = mesh if mesh is not None else _NullCtx()
-        with ctx:  # type: ignore[attr-defined]
+        with tracing.span(tracing.PLAN_BUILD), ctx:  # type: ignore[attr-defined]
             self.lowered = jitted.lower(*example_args)
             self.compiled = self.lowered.compile()
         self.init_seconds = time.perf_counter() - t0
@@ -104,7 +110,12 @@ class CommPlan:
         """Begin the exchange (async dispatch of the compiled executable)."""
         if self._freed:
             raise RuntimeError(f"plan {self.name!r} used after free()")
-        return self.compiled(*args)
+        t0 = time.perf_counter()
+        with tracing.span(tracing.PLAN_START):
+            out = self.compiled(*args)
+        self.start_seconds += time.perf_counter() - t0
+        self.starts += 1
+        return out
 
     @staticmethod
     def wait(out: Any) -> Any:
@@ -143,6 +154,11 @@ class _NullCtx:
         return False
 
 
+def _starts(plan: Any) -> int:
+    """A plan's own count of starts; a plan-like stand-in counts none."""
+    return getattr(plan, "starts", 0)
+
+
 class PlanCache:
     """Registry of initialized persistent plans (keyed by fn + abstract args).
 
@@ -153,7 +169,22 @@ class PlanCache:
     def __init__(self) -> None:
         self._plans: dict[Hashable, CommPlan] = {}
         self._lock = threading.Lock()
-        self.stats = PlanStats()
+        self._stats = PlanStats()
+        self._freed_starts = 0  # starts of the plans this cache has freed
+
+    @property
+    def stats(self) -> PlanStats:
+        """The cache's counters; ``starts`` sums the starts of every plan
+        it has held, as each plan counts them itself."""
+        with self._lock:
+            self._stats.starts = self._freed_starts + sum(
+                _starts(p) for p in self._plans.values())
+        return self._stats
+
+    def _free(self, plan: CommPlan) -> None:
+        self._freed_starts += _starts(plan)
+        plan.free()
+        self._stats.frees += 1
 
     def key_for(self, fn: Callable, args: Sequence[Any], extra: Hashable = ()) -> Hashable:
         flat, treedef = jax.tree.flatten(list(args))
@@ -187,14 +218,14 @@ class PlanCache:
         with self._lock:
             plan = self._plans.get(key)
             if plan is not None:
-                self.stats.cache_hits += 1
+                self._stats.cache_hits += 1
                 return plan
         plan = CommPlan(fn() if lazy_fn else fn, example_args=args,
                         **plan_kwargs)
         with self._lock:
             self._plans[key] = plan
-            self.stats.inits += 1
-            self.stats.init_seconds += plan.init_seconds
+            self._stats.inits += 1
+            self._stats.init_seconds += plan.init_seconds
         return plan
 
     def invalidate(self, predicate: Callable[[Hashable], bool] | None = None) -> int:
@@ -215,16 +246,14 @@ class PlanCache:
             doomed = [k for k in self._plans
                       if predicate is None or predicate(k)]
             for k in doomed:
-                self._plans.pop(k).free()
-                self.stats.frees += 1
-            self.stats.invalidations += len(doomed)
+                self._free(self._plans.pop(k))
+            self._stats.invalidations += len(doomed)
         return len(doomed)
 
     def free_all(self) -> None:
         with self._lock:
             for p in self._plans.values():
-                p.free()
-                self.stats.frees += 1
+                self._free(p)
             self._plans.clear()
 
     def invalidate_stale_epochs(self, live_epoch: int) -> int:
@@ -411,7 +440,6 @@ def persistent(
             plan = c.get_or_init(
                 f, args, donate_argnums=donate_argnums, mesh=mesh
             )
-            c.stats.starts += 1
             return plan.start(*args)
 
         wrapper.__wrapped__ = f  # type: ignore[attr-defined]

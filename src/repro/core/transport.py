@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import tracing
 
 # ---------------------------------------------------------------------------
 # Partitioner: the equal-partition (+padding) rule from the paper
@@ -941,11 +942,14 @@ def _deliver_group(
         arrived: list[tuple[Message, jax.Array]] = []
         for msg in messages:
             for part in msg.partitions():
-                buf = p.pack(x, part.src_start, part.shape)  # pack
-                buf = t.route(buf, part.hops)  # start/send
+                with tracing.scope(tracing.PACK):
+                    buf = p.pack(x, part.src_start, part.shape)
+                with tracing.scope(tracing.PERMUTE):  # start/send
+                    buf = t.route(buf, part.hops)
                 arrived.append((part, buf))
         for part, buf in arrived:  # unpack (disjoint ghost windows)
-            x = p.unpack(x, buf, part.dst_start, part.shape)
+            with tracing.scope(tracing.UNPACK):
+                x = p.unpack(x, buf, part.dst_start, part.shape)
         return x
 
     # Coalesced: one wire buffer and ONE composed collective per (partition
@@ -961,9 +965,12 @@ def _deliver_group(
         _chaos("round")
         for hops, parts in chains:
             layout = coalesced_layout(parts, hops, p, x0.dtype)
-            buf = p.pack_coalesced(x0, layout)
-            buf = t.route_composed(buf, hops)
-            x = p.unpack_coalesced(x, buf, layout)
+            with tracing.scope(tracing.PACK):
+                buf = p.pack_coalesced(x0, layout)
+            with tracing.scope(tracing.PERMUTE):
+                buf = t.route_composed(buf, hops)
+            with tracing.scope(tracing.UNPACK):
+                x = p.unpack_coalesced(x, buf, layout)
     return x
 
 

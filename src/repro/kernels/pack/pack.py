@@ -32,20 +32,10 @@ def _copy_convert_kernel(x_ref, o_ref, *, scale: float):
     o_ref[...] = x.astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("out_dtype", "scale", "block_lead", "block_lane", "interpret"),
-)
-def pack_2d(
-    slab: jax.Array,  # (lead, lane) view of a boundary slab
-    *,
-    out_dtype=None,
-    scale: float = 1.0,
-    block_lead: int = 256,
-    block_lane: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """Tiled contiguous copy (+convert/scale) of a 2-D slab view."""
+def _copy_2d(slab, *, out_dtype, scale, block_lead, block_lane, interpret,
+             name: str) -> jax.Array:
+    """Tiled contiguous copy (+convert/scale) of a 2-D slab view, as the
+    kernel ``name``."""
     lead, lane = slab.shape
     out_dtype = out_dtype or slab.dtype
     bl = min(block_lead, lead)
@@ -67,10 +57,30 @@ def pack_2d(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name=name,
     )(padded)
     if pl_lead or pl_lane:
         out = out[:lead, :lane]
     return out
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("out_dtype", "scale", "block_lead", "block_lane", "interpret"),
+)
+def pack_2d(
+    slab: jax.Array,  # (lead, lane) view of a boundary slab
+    *,
+    out_dtype=None,
+    scale: float = 1.0,
+    block_lead: int = 256,
+    block_lane: int = 256,
+    interpret: bool = False,
+) -> jax.Array:
+    """Tiled contiguous copy (+convert/scale) of a 2-D slab view."""
+    return _copy_2d(slab, out_dtype=out_dtype, scale=scale,
+                    block_lead=block_lead, block_lane=block_lane,
+                    interpret=interpret, name="pack_2d")
 
 
 @functools.partial(
@@ -87,11 +97,7 @@ def unpack_2d(
     interpret: bool = False,
 ) -> jax.Array:
     """Inverse of :func:`pack_2d` (convert back, inverse scale)."""
-    return pack_2d(
-        buf,
-        out_dtype=out_dtype,
-        scale=1.0 / scale if scale != 1.0 else 1.0,
-        block_lead=block_lead,
-        block_lane=block_lane,
-        interpret=interpret,
-    )
+    return _copy_2d(buf, out_dtype=out_dtype,
+                    scale=1.0 / scale if scale != 1.0 else 1.0,
+                    block_lead=block_lead, block_lane=block_lane,
+                    interpret=interpret, name="unpack_2d")

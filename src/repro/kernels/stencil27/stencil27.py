@@ -123,4 +123,5 @@ def stencil27(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="stencil27",
     )(w.reshape(27).astype(jnp.float32), x)

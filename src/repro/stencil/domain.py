@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import tracing
 from repro.core.halo import HaloSpec, ghost_pspec
 
 
@@ -348,12 +349,13 @@ def stencil27_update(
     w = jnp.asarray(weights)
 
     def update(xl: jax.Array) -> jax.Array:
-        xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
-        if impl == "pallas":
-            interior = stencil27(xp, w, interpret=interpret)
-        else:
-            interior = stencil27_ref(xp, w)
-        return jax.lax.dynamic_update_slice(xl, interior, (1, 1, 0))
+        with tracing.scope(tracing.UPDATE):
+            xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
+            if impl == "pallas":
+                interior = stencil27(xp, w, interpret=interpret)
+            else:
+                interior = stencil27_ref(xp, w)
+            return jax.lax.dynamic_update_slice(xl, interior, (1, 1, 0))
 
     return update
 

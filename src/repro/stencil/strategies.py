@@ -39,7 +39,7 @@ from typing import Callable, ClassVar
 import jax
 from jax.sharding import Mesh
 
-from repro.core import compat
+from repro.core import compat, tracing
 from repro.core.autotune import AUTO
 from repro.core.halo import (
     HaloSpec,
@@ -242,7 +242,8 @@ class ExchangeStrategy(abc.ABC):
         update = self.update_fn
 
         def step(x: jax.Array) -> jax.Array:
-            x = exchange(x, spec)
+            with tracing.scope(tracing.EXCHANGE):
+                x = exchange(x, spec)
             if update is not None:
                 x = update(x)
             return x
@@ -330,6 +331,13 @@ class ExchangeStrategy(abc.ABC):
     def compiled_text(self, example: jax.Array) -> str:
         """Post-optimization HLO of the step (for overlap/HLO analysis)."""
         raise NotImplementedError(f"{self.name} has no compiled plan")
+
+    def plan_counters(self) -> dict | None:
+        """The persistent plan's own counters: ``starts`` (dispatches of
+        its executable), ``start_seconds`` (host time in them) and
+        ``init_seconds`` (its trace, lower and compile); ``None`` for a
+        strategy without a plan."""
+        return None
 
 
 # ---------------------------------------------------------------------------
@@ -515,6 +523,13 @@ class PersistentStrategy(ExchangeStrategy):
         assert self._plan is not None
         return self._plan.as_text()
 
+    def plan_counters(self) -> dict | None:
+        p = self._plan
+        if p is None:
+            return None
+        return {"starts": p.starts, "start_seconds": p.start_seconds,
+                "init_seconds": p.init_seconds}
+
 
 @register_strategy
 class PartitionedStrategy(PersistentStrategy):
@@ -559,7 +574,8 @@ class FusedStrategy(PersistentStrategy):
         update = self.update_fn
 
         def step(x: jax.Array) -> jax.Array:
-            x = exchange_fused(x, spec)
+            with tracing.scope(tracing.EXCHANGE):
+                x = exchange_fused(x, spec)
             if update is not None:
                 x = update(x)
             return x
@@ -602,7 +618,8 @@ class OverlapStrategy(PersistentStrategy):
         update = self.update_fn
 
         def step(x: jax.Array) -> jax.Array:
-            fresh = exchange(x, spec)  # boundary exchange in flight...
+            with tracing.scope(tracing.EXCHANGE):
+                fresh = exchange(x, spec)  # boundary exchange in flight...
             if update is None:
                 return fresh
             # ...while the deep interior computes from the stale buffer
@@ -871,3 +888,6 @@ class AutoStrategy(ExchangeStrategy):
     def compiled_text(self, example: jax.Array) -> str:
         self._resolve(example)
         return self._inner.compiled_text(example)
+
+    def plan_counters(self) -> dict | None:
+        return None if self._inner is None else self._inner.plan_counters()

@@ -9,6 +9,8 @@ only by the worker that runs these tests, and where it cannot be described
 every test here skips.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -69,14 +71,23 @@ def _compile(fn, *args):
     return compiled
 
 
+def _kernels(compiled) -> set[str]:
+    """The names of the kernels' custom-call instructions, suffix aside:
+    what a profiler trace shows for each kernel."""
+    return set(re.findall(r"%([a-z0-9_]+)(?:\.\d+)? = \S+ custom-call\(",
+                          compiled.as_text()))
+
+
 @pytest.mark.parametrize("wire", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "f32-bf16"])
 @pytest.mark.parametrize("view", FACE_VIEWS, ids=["z-face", "y-face"])
 def test_pack_unpack_compile_at_face_views(one_chip, view, wire):
     face = jax.ShapeDtypeStruct(view, jnp.float32, sharding=one_chip)
-    _compile(lambda s: pack_2d(s, out_dtype=wire), face)
+    assert _kernels(_compile(lambda s: pack_2d(s, out_dtype=wire),
+                             face)) == {"pack_2d"}
     buf = jax.ShapeDtypeStruct(view, wire, sharding=one_chip)
-    _compile(lambda b: unpack_2d(b, out_dtype=jnp.float32), buf)
+    assert _kernels(_compile(lambda b: unpack_2d(b, out_dtype=jnp.float32),
+                             buf)) == {"unpack_2d"}
 
 
 @pytest.mark.parametrize("schedule", ["partitioned", "fused"])
@@ -106,8 +117,9 @@ def test_coalesced_pack_compiles_at_smoke_layout(one_chip, schedule):
                                         layout)
         return x
 
-    _compile(step, jax.ShapeDtypeStruct(BLOCK, jnp.float32,
-                                        sharding=one_chip))
+    compiled = _compile(step, jax.ShapeDtypeStruct(BLOCK, jnp.float32,
+                                                   sharding=one_chip))
+    assert _kernels(compiled) == {"pack_2d", "unpack_2d"}
 
 
 @pytest.mark.parametrize("shape", list(STENCIL_INPUTS.values()),
@@ -115,4 +127,4 @@ def test_coalesced_pack_compiles_at_smoke_layout(one_chip, schedule):
 def test_stencil27_compiles_at_smoke_windows(one_chip, shape):
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((3, 3, 3), jnp.float32, sharding=one_chip)
-    _compile(stencil27, x, w)
+    assert _kernels(_compile(stencil27, x, w)) == {"stencil27"}
