@@ -18,7 +18,8 @@ Scopes, innermost wins:
 ``pack``      the packer staging a message or wire buffer
 ``permute``   the transport moving it (the collectives)
 ``unpack``    the packer writing arrivals into the ghost rims
-``update``    the local 27-point update: wrap, stencil, write-back
+``update``    the local 27-point update: the in-place kernel, or the XLA
+              path's wrap, stencil and write-back
 
 Spans: ``plan.build`` around a persistent plan's trace, lower and compile
 (one inside a run of steps is a recompile); ``plan.start`` around the

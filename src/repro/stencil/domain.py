@@ -332,15 +332,17 @@ def stencil27_update(
     """The 27-point update of one local ghosted 3-D block.
 
     The block carries ghost rims on its two leading (decomposed) axes and
-    none on its last axis, which is undecomposed and periodic: the update
-    wraps that axis locally, applies the stencil and writes the new
-    interior back, leaving the ghost rims untouched (the
-    :func:`interior_halo_split` contract, so ``overlap`` can split it).
-    One exchange plus this update is one Comb cycle of
-    :func:`periodic_oracle_step`.  ``impl`` names the stencil: ``"xla"``
-    is :func:`repro.kernels.stencil27.stencil27_ref` under XLA,
-    ``"pallas"`` the :func:`repro.kernels.stencil27.stencil27` kernel
-    (``interpret`` runs it in the Pallas interpreter, off the chip).
+    none on its last axis, which is undecomposed and periodic.  The update
+    replaces the interior with the 27-point sum, wrapping that axis, and
+    leaves the ghost rims untouched (the :func:`interior_halo_split`
+    contract, so ``overlap`` can split it).  One exchange plus this update
+    is one Comb cycle of :func:`periodic_oracle_step`.  ``impl`` names the
+    stencil: ``"xla"`` wraps the last axis by concatenation, applies
+    :func:`repro.kernels.stencil27.stencil27_ref` under XLA and writes the
+    interior back, the independent reference; ``"pallas"`` is one call of
+    the :func:`repro.kernels.stencil27.stencil27` kernel, which updates
+    the block in place (``interpret`` runs it in the Pallas interpreter,
+    off the chip).
     """
     from repro.kernels.stencil27 import stencil27, stencil27_ref
 
@@ -350,12 +352,11 @@ def stencil27_update(
 
     def update(xl: jax.Array) -> jax.Array:
         with tracing.scope(tracing.UPDATE):
-            xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
             if impl == "pallas":
-                interior = stencil27(xp, w, interpret=interpret)
-            else:
-                interior = stencil27_ref(xp, w)
-            return jax.lax.dynamic_update_slice(xl, interior, (1, 1, 0))
+                return stencil27(xl, w, interpret=interpret)
+            xp = jnp.concatenate([xl[..., -1:], xl, xl[..., :1]], axis=-1)
+            return jax.lax.dynamic_update_slice(
+                xl, stencil27_ref(xp, w), (1, 1, 0))
 
     return update
 

@@ -28,14 +28,16 @@ from repro.kernels.stencil27 import stencil27
 BLOCK = (514, 1026, 1024)
 #: its z- and y-face slabs through the lane-dense 2-D view
 FACE_VIEWS = [(1026, 1024), (514, 1024)]
-#: what the 27-point update hands the stencil kernel (lane axis wrapped):
-#: the whole block, and ``overlap``'s deep-interior and shell windows
+#: what the 27-point update hands the stencil kernel, the stored block as
+#: it is: the whole block, and ``overlap``'s deep-interior and shell windows
 STENCIL_INPUTS = {
-    "block": (514, 1026, 1026),
-    "overlap-interior": (512, 1024, 1026),
-    "overlap-z-shell": (3, 1026, 1026),
-    "overlap-y-shell": (514, 3, 1026),
+    "block": (514, 1026, 1024),
+    "overlap-interior": (512, 1024, 1024),
+    "overlap-z-shell": (3, 1026, 1024),
+    "overlap-y-shell": (514, 3, 1024),
 }
+#: the benchmark's FIG2 block (1 GiB f32), which the kernel updates in place
+FIG2_BLOCK = (512, 512, 1024)
 
 
 @pytest.fixture(scope="module")
@@ -128,3 +130,18 @@ def test_stencil27_compiles_at_smoke_windows(one_chip, shape):
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((3, 3, 3), jnp.float32, sharding=one_chip)
     assert _kernels(_compile(stencil27, x, w)) == {"stencil27"}
+
+
+def test_stencil27_updates_fig2_block_in_place(one_chip):
+    """One ``stencil27`` custom call that writes into its operand's buffer
+    (donated), and no temporaries: no wrap copy, no write-back."""
+    x = jax.ShapeDtypeStruct(FIG2_BLOCK, jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 3, 3), jnp.float32, sharding=one_chip)
+    compiled = stencil27.lower(x, w).compile()
+    text = compiled.as_text()
+    assert _kernels(compiled) == {"stencil27"}
+    assert len(re.findall(r" custom-call\(", text)) == 1
+    assert re.search(r"output_to_operand_aliasing=\{\{\}: \(1, \{\}\)\}", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes == 0
+    assert memory.alias_size_in_bytes == 4 * 512 * 512 * 1024
