@@ -72,7 +72,7 @@ def test_tiny_cell_runs_correct(root, name):
 
 
 @pytest.mark.parametrize("name", ["tiny.cycle", "tiny.comm.4chip",
-                                  "tiny.comm"])
+                                  "tiny.comm", "tiny_msg.comm.4chip"])
 def test_control_is_not_correct(root, name):
     """The program's bf16 wire packer, the precision below float32."""
     _, result, _ = one_run(root, name, packer="bf16")
@@ -118,7 +118,7 @@ FAULTS = ["unchanged", "no_exchange", "half", "altered_ghost",
 
 
 @pytest.mark.parametrize("name", ["tiny.cycle", "tiny.comm.4chip",
-                                  "tiny.comm"])
+                                  "tiny.comm", "tiny_msg.comm.4chip"])
 @pytest.mark.parametrize("kind", FAULTS)
 def test_planted_fault_is_not_correct(root, name, kind):
     _, result, _ = one_run(root, name, wrap=lambda d: Fault(d, kind))

@@ -60,15 +60,36 @@ def test_configs_cells_and_metrics_keep_the_rules():
     assert len(names) == len(set(names))
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_resolves_and_reports(cell):
-    c = spec.load_cell(cell)
-    e2e = {m.name for m in c.end_to_end}
-    assert "setup_s" in e2e and len(e2e) >= 2
+def check_cell_reports(cell: str, root=spec.ROOT) -> list[str]:
+    """The rule that every cell keeps, whatever files and entries added it:
+    it reports ``setup_s`` and exactly one other end-to-end metric, whose
+    quantity (its name before the first ``.``) is its traffic's
+    ``metric``, as ``run.run_cell`` maps it; that metric lists the cell in
+    its ``workloads``, and no other metric but ``setup_s`` does; and each
+    per-layer metric of the cell has a reader and moves one of the cell's
+    end-to-end metrics.  Returns the cell's end-to-end names, sorted."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    c = spec.load_cell(cell, root)
+    e2e = sorted(m.name for m in c.end_to_end)
+    others = [n for n in e2e if n != "setup_s"]
+    assert "setup_s" in e2e and len(others) == 1, (cell, e2e)
+    assert others[0].split(".")[0] == c.traffic["metric"], (cell, e2e)
+    lists = {}
+    for m in bench["end_to_end"]:
+        if m["name"] != "setup_s":
+            assert "workloads" in m, m["name"]
+            lists[m["name"]] = m["workloads"]
+    assert [n for n, cells in lists.items() if cell in cells] == others
     assert c.per_layer
     for m in c.per_layer:
-        assert callable(spec.load_reader(m.name))
+        assert callable(spec.load_reader(m.name, root))
         assert m.moves in e2e
+    return e2e
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_resolves_and_reports(cell):
+    check_cell_reports(cell)
 
 
 def test_mesh_follows_from_the_chips():
@@ -103,20 +124,23 @@ def test_a_configuration_extends_another(tmp_path):
 
 
 def test_cells_report_the_metrics_of_their_traffic():
-    got = {cell: sorted(m.name for m in spec.load_cell(cell).end_to_end)
-           for cell in CELLS}
-    assert got == {
-        "fig2_weak.cycle": ["cycle_us", "setup_s"],
-        "fig2_weak.comm_pipelined": ["exchange_us.pipelined", "setup_s"],
-        "fig2_weak.comm.4chip": ["exchange_us.4chip", "setup_s"],
-        "fig4_msg12k.comm": ["exchange_us", "setup_s"],
-    }
+    """The rule holds for every cell, and each cell named here keeps its
+    metrics; a cell added by files and entries needs no edit here."""
+    got = {cell: check_cell_reports(cell) for cell in CELLS}
+    assert got["fig2_weak.cycle"] == ["cycle_us", "setup_s"]
+    assert got["fig2_weak.comm_pipelined"] == ["exchange_us.pipelined",
+                                               "setup_s"]
+    assert got["fig2_weak.comm.4chip"] == ["exchange_us.4chip", "setup_s"]
+    assert got["fig4_msg12k.comm"] == ["exchange_us", "setup_s"]
+    assert got["fig4_msg12k.comm.4chip"] == ["exchange_us", "setup_s"]
     layer = {cell: {m.name for m in spec.load_cell(cell).per_layer}
              for cell in CELLS}
     assert "collective_us.exchange" in layer["fig2_weak.comm.4chip"]
     assert "pack_roofline.exchange" not in layer["fig4_msg12k.comm"]
     assert "update_roofline" in layer["fig2_weak.cycle"]
     assert "dispatch_us.exchange" not in layer["fig2_weak.comm_pipelined"]
+    assert {"dispatch_us.exchange", "exchange_p95_us", "idle_share.exchange",
+            "collective_us.exchange.msg12k"} <= layer["fig4_msg12k.comm.4chip"]
 
 
 @pytest.mark.parametrize("bad", ["a b", "a,b", "a/b", "", ".x", "x" * 65,
@@ -202,5 +226,10 @@ def test_a_cell_traffic_and_metric_are_added_by_files_alone(tmp_path):
     assert "cycles_seen" in [m.name for m in cell.per_layer]
     assert spec.load_reader("cycles_seen", root)(
         SimpleNamespace(cycles=7)) == 7
+    # every cell of the copy, the added one too, keeps the spec's rule
+    added = [w["name"] for w in bench["workloads"]]
+    assert added == CELLS + ["fig4_msg48k.comm_batched"]
+    for name in added:
+        check_cell_reports(name, root)
     # the real checkout is untouched
     assert "fig4_msg48k.comm_batched" not in CELLS

@@ -131,6 +131,25 @@ def test_collectives_on_four_chips():
         summary("fig2_weak.comm.4chip", chips=8)
 
 
+def test_collectives_at_small_faces_read_as_the_transport():
+    """The four-chip FIG4 cell's collective reader is the transport's: on
+    the same four-chip trace both read the same, and only that cell has
+    it among its per-layer metrics."""
+    s = summary("fig2_weak.comm.4chip", chips=4)
+    ctx = context("fig2_weak.comm.4chip", s, block=[16, 64, 256])
+    msg = spec.load_reader("collective_us.exchange.msg12k")(ctx)
+    assert msg > 0
+    assert msg == spec.load_reader("collective_us.exchange")(ctx)
+    has = {cell: "collective_us.exchange.msg12k" in
+           {m.name for m in spec.load_cell(cell).per_layer}
+           for cell in ("fig4_msg12k.comm.4chip", "fig4_msg12k.comm",
+                        "fig2_weak.comm_pipelined", "fig2_weak.cycle",
+                        "fig2_weak.comm.4chip")}
+    assert has == {"fig4_msg12k.comm.4chip": True, "fig4_msg12k.comm": False,
+                   "fig2_weak.comm_pipelined": False,
+                   "fig2_weak.cycle": False, "fig2_weak.comm.4chip": False}
+
+
 def test_readers_find_nothing_in_an_empty_window():
     s = tr.Summary(window=(0.0, 1e9), ops={0: []}, busy={0: []},
                    step_starts={0: []}, host=[])
@@ -139,8 +158,8 @@ def test_readers_find_nothing_in_an_empty_window():
     ctx = SimpleNamespace(trace=s, cycles=0, work={}, peaks=PEAKS,
                           window=window)
     for name in ("update_roofline", "pack_roofline.exchange",
-                 "collective_us.exchange", "dispatch_us.exchange",
-                 "exchange_p95_us"):
+                 "collective_us.exchange", "collective_us.exchange.msg12k",
+                 "dispatch_us.exchange", "exchange_p95_us"):
         assert spec.load_reader(name)(ctx) is None
     assert s.busy_s == 0 and s.breakdown()["idle_gaps"] == [["host", 1.0]]
 

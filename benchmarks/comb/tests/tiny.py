@@ -17,6 +17,7 @@ TINY = {
     "tiny.comm.4chip": ("fig2_weak.comm.4chip", 4, [8, 16, 128]),
     "tiny.cycle.4chip": ("fig2_weak.cycle", 4, [8, 16, 128]),
     "tiny_msg.comm": ("fig4_msg12k.comm", 1, [6, 6, 128]),
+    "tiny_msg.comm.4chip": ("fig4_msg12k.comm.4chip", 4, [6, 6, 128]),
 }
 
 
